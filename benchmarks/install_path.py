@@ -4,15 +4,16 @@ reference chain (DESIGN.md §11).
 Three measurements, each a gate in ``BENCH_install_path.json``:
 
 * **install latency** — scatter G staged pages into the batch cache,
-  fused (one program per dtype-group) vs the per-leaf ``slice -> view ->
+  fused (one program per staging shape) vs the per-leaf ``slice -> view ->
   .at[].set`` chain, across page sizes x group depths x buffer counts.
   Gate: >= 1.5x faster at group depth >= 4.
 * **hop counts** — structural, not timed: a fused spill crosses D2H
   once (the packed page) where the per-leaf chain pays one readback per
   leaf; a batched resident writeback group crosses H2C once
   (``TieredStore.write_pages``) where the loop pays one per page.
-* **parity** — the pallas kernels under ``interpret=True`` and the jit
-  path must reproduce the reference bytes exactly (asserted, recorded).
+* **parity** — the pallas kernels (compiled on a TPU, interpreted on
+  the CPU) and the jit path must reproduce the reference bytes exactly
+  (asserted, recorded).
 
     PYTHONPATH=src python -m benchmarks.install_path [--quick|--smoke]
         [--json PATH]
@@ -93,7 +94,7 @@ def _bench_spill(max_len: int) -> dict:
     ref_s = time_call(lambda: ops.pack_page_ref(layout, leaves),
                       repeats=5, warmup=1)
     fused_s = time_call(
-        lambda: np.asarray(ops.pack_page(layout, leaves, mode="jit")),
+        lambda: ops.pack_page(layout, leaves, mode="jit"),
         repeats=5, warmup=2)
     emit(f"spill_pack[pb={layout.page_bytes}]", fused_s * 1e6,
          f"ref_us={ref_s*1e6:.1f};d2h_fused=1;d2h_ref={len(leaves)}")
@@ -133,16 +134,14 @@ def _check_parity(max_len: int) -> bool:
     leaves = jax.tree.leaves(_randomize(single, 5))
     ref_page = ops.pack_page_ref(layout, leaves)
     for mode in ("jit", "pallas"):
-        got = np.asarray(ops.pack_page(layout, leaves, mode=mode,
-                                       interpret=True))
+        got = ops.pack_page(layout, leaves, mode=mode)
         if not np.array_equal(got, ref_page):
             return False
     pages = jnp.stack([jnp.asarray(ref_page)] * 2)
     slots = [3, 0]
     want = ops.install_pages_ref(layout, flat_b, pages, slots)
     for mode in ("jit", "pallas"):
-        got = ops.install_pages(layout, flat_b, pages, slots,
-                                mode=mode, interpret=True)
+        got = ops.install_pages(layout, flat_b, pages, slots, mode=mode)
         for g, w in zip(got, want):
             if not np.array_equal(
                     np.asarray(g).reshape(-1).view(np.uint8),

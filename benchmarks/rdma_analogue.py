@@ -1,73 +1,55 @@
 """Paper Figs 19-20 (RDMA read/write): the device<->device ICI path.
 
 RDMA on the SoC SmartNIC is the 'easy API on a separate link' path; on a
-TPU pod that's ICI device<->device transfer.  This bench runs in a
-subprocess with 8 host devices and measures jax.device_put between devices
-(write analogue) and cross-device gather (read analogue), projecting onto
-the ICI model.  Reproduces the paper's qualitative finding: the
-RDMA/ICI-style path is slower than the raw DMA path but trivial to use.
+TPU host that's the ICI device<->device transfer.  This bench runs in
+the calling process on the devices present — a chip belongs to one
+process, so it never starts a child — and times jax.device_put from
+device 0 to device 1 (write analogue) and back (read analogue),
+projecting onto the ICI model.  With fewer than two devices it says it
+cannot run and reports no number.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from benchmarks.common import emit
 
-_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import json, time
-import jax, jax.numpy as jnp
-import numpy as np
 
-out = []
-for size in SIZES:
-    n = size // 4
-    x = jnp.zeros((n,), jnp.float32)
-    d0, d1 = jax.devices()[0], jax.devices()[1]
-    x = jax.device_put(x, d0)
-    x.block_until_ready()
-    # write analogue: push to remote device
-    ts = []
-    for _ in range(3):
+def _median_put(x, dev, repeats: int = 3) -> tuple:
+    ts, y = [], None
+    for _ in range(repeats):
         t0 = time.perf_counter()
-        y = jax.device_put(x, d1)
+        y = jax.device_put(x, dev)
         y.block_until_ready()
         ts.append(time.perf_counter() - t0)
-    t_w = float(np.median(ts))
-    # read analogue: pull back
-    ts = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        z = jax.device_put(y, d0)
-        z.block_until_ready()
-        ts.append(time.perf_counter() - t0)
-    t_r = float(np.median(ts))
-    out.append({"size": size, "t_write": t_w, "t_read": t_r})
-print(json.dumps(out))
-"""
+    return float(np.median(ts)), y
 
 
 def run(quick: bool = False) -> None:
+    devs = jax.devices()
+    if len(devs) < 2:
+        print(f"# fig19_20_rdma: cannot run, needs 2 devices and found "
+              f"{len(devs)} ({devs[0].platform})", flush=True)
+        return
     sizes = [1 << 18, 1 << 20] if quick else [1 << 18, 1 << 20, 1 << 22]
-    code = f"SIZES = {sizes}\n" + _CHILD
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    res = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=300)
-    rows = json.loads(res.stdout.strip().splitlines()[-1])
     from repro.core.analytical import bandwidth_gbps, tpu_ici_path
     from repro.core.channels import Direction
     ici = tpu_ici_path()
-    for r in rows:
-        size = r["size"]
-        for op, t in (("write", r["t_write"]), ("read", r["t_read"])):
+    d0, d1 = devs[0], devs[1]
+    for size in sizes:
+        x = jax.device_put(jnp.zeros((size // 4,), jnp.float32), d0)
+        x.block_until_ready()
+        t_w, y = _median_put(x, d1)
+        t_r, _ = _median_put(y, d0)
+        for op, t in (("write", t_w), ("read", t_r)):
             proj = bandwidth_gbps(ici, size, 1, Direction.C2H)
             emit(f"fig19_20_rdma_{op}_{size >> 10}KB", t * 1e6,
-                 f"meas={size/t/1e9:.2f}GB/s ici_model={proj:.1f}GB/s")
+                 f"meas={size/t/1e9:.2f}GB/s ici_model={proj:.1f}GB/s "
+                 f"device={d0.platform}:{d0.device_kind}")
 
 
 if __name__ == "__main__":

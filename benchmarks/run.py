@@ -17,6 +17,7 @@ from benchmarks import (chaos, common, completion_modes, contention,
                         install_path, kv_capacity, offload_step, overlap,
                         rdma_analogue, serve_slo, vmem_stream)
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     ("fig8_vmem_stream", vmem_stream),
@@ -78,6 +79,7 @@ def main(argv=None) -> None:
                          "lands in every BENCH_*.json; on by default "
                          "with --smoke)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     quick = args.quick or args.smoke
     common.set_bench_seed(args.seed)
     if args.trace_out:

@@ -1,7 +1,7 @@
 """Paper Fig 8: on-chip (BRAM -> VMEM) tier bandwidth vs transfer size.
 
-Runs the Pallas streamcopy kernel (interpret mode: correctness + structural
-block accounting) and reports the *modeled* TPU HBM<->VMEM pipeline
+Runs the Pallas streamcopy kernel (compiled on a TPU, interpreted on the
+CPU) and reports the *modeled* TPU HBM<->VMEM pipeline
 bandwidth per (block size x buffer count), plus the paper-path projection.
 Derived column shows modeled bandwidth: with n buffers the pipeline hides
 min(n-1, 1) of the two DMA legs — the same multi-channel aggregation the
@@ -40,8 +40,7 @@ def run(quick: bool = False) -> None:
             x = jnp.asarray(np.random.default_rng(0).standard_normal(
                 (rows_total, COLS)), jnp.float32)
             fn = lambda: ops.stream_copy(
-                x, block_rows=br, n_buffers=nb,
-                interpret=True).block_until_ready()
+                x, block_rows=br, n_buffers=nb).block_until_ready()
             t = time_call(fn, repeats=2, warmup=1)
             block_bytes = br * COLS * 4
             modeled = modeled_vmem_gbps(block_bytes, nb)

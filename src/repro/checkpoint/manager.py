@@ -33,15 +33,6 @@ import numpy as np
 from repro.core.engine import MemoryEngine
 
 
-def _flatten_with_path(tree):
-    # jax.tree.flatten_with_path only exists on newer jax; 0.4.37 has the
-    # tree_util spelling.
-    fn = getattr(jax.tree, "flatten_with_path", None)
-    if fn is None:
-        fn = jax.tree_util.tree_flatten_with_path
-    return fn(tree)
-
-
 def _leaf_name(path) -> str:
     parts = []
     for p in path:
@@ -73,7 +64,7 @@ class CheckpointManager:
     # -- save ---------------------------------------------------------------
     def save(self, step: int, tree: Any, block: bool = True) -> None:
         self.wait()  # one async save at a time
-        leaves_dev, treedef = _flatten_with_path(tree)
+        leaves_dev, treedef = jax.tree.flatten_with_path(tree)
         paths = [p for p, _ in leaves_dev]
         join = self.engine.read_tree_async([l for _, l in leaves_dev])
 
@@ -156,7 +147,7 @@ class CheckpointManager:
         self.wait()
         reuse_addrs = {e["name"]: e for e in reuse["leaves"]} if reuse \
             else {}
-        leaves_dev, treedef = _flatten_with_path(tree)
+        leaves_dev, treedef = jax.tree.flatten_with_path(tree)
         host_leaves = self.engine.read_tree_async(
             [l for _, l in leaves_dev])()
         entries: List[Dict[str, Any]] = []
@@ -200,7 +191,7 @@ class CheckpointManager:
         import jax.numpy as jnp
         from repro.rmem.verbs import MemoryRegion, QueuePair
         by_name = {e["name"]: e for e in manifest["leaves"]}
-        leaves_like, treedef = _flatten_with_path(like)
+        leaves_like, treedef = jax.tree.flatten_with_path(like)
         out = []
         with QueuePair(node) as qp:
             for path, leaf in leaves_like:
@@ -253,7 +244,7 @@ class CheckpointManager:
         with open(os.path.join(d, "manifest.json")) as f:
             manifest = json.load(f)
         by_name = {e["name"]: e for e in manifest["leaves"]}
-        leaves_like, treedef = _flatten_with_path(like)
+        leaves_like, treedef = jax.tree.flatten_with_path(like)
         shard_leaves = (jax.tree.leaves(shardings)
                         if shardings is not None else [None] * len(leaves_like))
         out = []

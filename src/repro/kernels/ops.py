@@ -1,8 +1,9 @@
 """Jit'd public wrappers for all Pallas kernels.
 
-On this container the kernels execute with ``interpret=True`` (CPU); on a
-real TPU set ``interpret=False`` (default chosen from the backend).  The
-model stack routes through these when ``ModelConfig.use_pallas`` is set.
+``interpret`` defaults from the backend: compiled kernels on a TPU, the
+Pallas interpreter on the CPU (the tests run there with
+``JAX_PLATFORMS=cpu``).  The model stack routes through these when
+``ModelConfig.use_pallas`` is set.
 """
 from __future__ import annotations
 
@@ -48,11 +49,10 @@ def rg_lru_scan(a, b, h0=None, *, block_t: int = 64, block_w: int = 256,
                    interpret=interp)
 
 
-def pack_page(layout, leaves, *, mode: str = "auto", n_buffers: int = 2,
+def pack_page(layout, leaves, *, mode: str = "auto",
               interpret: Optional[bool] = None):
     interp = _default_interpret() if interpret is None else interpret
-    return _pi.pack_page(layout, leaves, mode=mode, n_buffers=n_buffers,
-                         interpret=interp)
+    return _pi.pack_page(layout, leaves, mode=mode, interpret=interp)
 
 
 def install_pages(layout, batch_leaves, pages, slots, *,

@@ -14,11 +14,10 @@ with layout-driven fused paths:
   jit fallback, the host reference, and (via the unchanged byte format)
   the checksum plane.
 * ``install_pages`` — scatter G staged pages into the batch cache tree.
-  ``mode="pallas"`` runs one ``pallas_call`` per dtype-group with
-  double-buffered VMEM staging (DMA-in of page k+1 overlaps the scatter
-  of page k — the in-kernel analogue of the §3.3 two-hop overlap);
-  ``mode="jit"`` is a single fused XLA program (the production path on
-  CPU backends, one dispatch instead of ``n_leaves × G``);
+  ``mode="pallas"`` runs one ``pallas_call`` whose copies are all
+  HBM->HBM DMAs: each leaf's segment of the staged pages (an XLA
+  slice + bitcast outside the kernel) lands in its slot row of the
+  aliased batch leaf; ``mode="jit"`` is a single fused XLA program;
   ``mode="ref"`` is the per-leaf legacy chain, kept as the parity
   oracle.
 * ``pack_page`` — the scatter's gather twin for spill: pack one slot's
@@ -34,18 +33,19 @@ Byte format contract: a page is the concatenation of every leaf's C
 fused and per-leaf paths (and the §9 checksums stamped over either) are
 bit-exact interchangeable.
 
-Kernel hazard discipline (the §2 ``streamcopy`` table, minus the put
-leg — scatter stores are synchronous in-kernel): per VMEM slot s and
-page g (slot = g % n_buffers): wait get(g) -> scatter leaves of g ->
-start get(g + n_buffers).  On this container the kernels run with
-``interpret=True``; ``mode="auto"`` picks pallas on TPU and the fused
-jit program elsewhere.
+On a TPU the kernels never load or store an HBM ref: every byte moves
+by ``make_async_copy``, and no VMEM is staged, so the kernels compile at
+any ``max_len``.  Install hazard discipline: page g's copies run on lane
+g % n_buffers, and a lane's previous page is waited before reuse.
+``mode="auto"`` picks pallas (compiled) on TPU and the fused jit
+program elsewhere; the CPU tests run the pallas kernels with
+``interpret=True``.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,25 +90,57 @@ class PageLayout:
     page_bytes: int
     leaves: Tuple[LeafSpec, ...]
 
-    def kernel_groups(self) -> Dict[str, List[LeafSpec]]:
-        """Leaves the fused kernels can handle, grouped by dtype: a
-        slot axis exists, ranks agree, and the leaf's byte offset is
-        aligned to its itemsize (bitcastable in place)."""
-        groups: Dict[str, List[LeafSpec]] = {}
-        for sp in self.leaves:
-            if sp.slot_axis is None or len(sp.shape) != len(sp.batch_shape):
-                continue
-            if sp.offset % sp.itemsize or sp.nbytes == 0:
-                continue
-            groups.setdefault(sp.dtype, []).append(sp)
-        return groups
+    def kernel_leaves(self) -> Tuple[LeafSpec, ...]:
+        """Leaves the fused kernels copy: a slot axis exists, ranks
+        agree, and one slot's row folds into whole (sublane, 128) tiles
+        (a TPU DMA may only slice an HBM ref along untiled dims)."""
+        return tuple(sp for sp in self.leaves
+                     if sp.slot_axis is not None and sp.nbytes
+                     and len(sp.shape) == len(sp.batch_shape)
+                     and _row_elems(sp) % (_sublanes(sp) * _LANES) == 0)
 
     def fallback_indices(self) -> Tuple[int, ...]:
         """Leaf indices the kernels skip (installed by the jit path)."""
-        covered = {sp.index for g in self.kernel_groups().values()
-                   for sp in g}
+        covered = {sp.index for sp in self.kernel_leaves()}
         return tuple(sp.index for sp in self.leaves
                      if sp.index not in covered)
+
+    @property
+    def word_bytes(self) -> int:
+        """Widest unsigned word (4, 2 or 1 bytes) that every leaf starts
+        on and the page ends on.  Device-side pages travel as words of
+        this width: the TPU compiler takes minutes over programs that
+        reshape large uint8 arrays, and seconds over 32-bit words."""
+        return next(k for k in (4, 2, 1)
+                    if self.page_bytes % k == 0
+                    and all(sp.offset % k == 0 for sp in self.leaves))
+
+    @property
+    def word_dtype(self) -> np.dtype:
+        return np.dtype(f"uint{8 * self.word_bytes}")
+
+
+_LANES = 128
+
+
+def _sublanes(sp: LeafSpec) -> int:
+    # rows of one native TPU tile: 8 for 32-bit, 16 for 16-bit, 32 for
+    # 8-bit dtypes
+    return 8 * max(1, 4 // sp.itemsize)
+
+
+def _row_elems(sp: LeafSpec) -> int:
+    """Elements of one slot's row: the dims after the slot axis."""
+    return int(np.prod(sp.shape[sp.slot_axis + 1:], dtype=np.int64))
+
+
+def _tiled(sp: LeafSpec, shape) -> Tuple[int, ...]:
+    """``shape`` through the slot axis, then the row folded into whole
+    (sublane, 128) tiles, so the kernel's DMAs slice major dims only."""
+    a = sp.slot_axis + 1
+    sub = _sublanes(sp)
+    return tuple(shape[:a]) + (_row_elems(sp) // (sub * _LANES), sub,
+                               _LANES)
 
 
 def _slot_axis(bshape, oshape, batch: int) -> Optional[int]:
@@ -160,18 +192,21 @@ def page_layout(single_tree, batch_tree, batch: int) -> PageLayout:
 # byte <-> dtype plumbing (bit-exact with numpy .view on both ends)
 # ---------------------------------------------------------------------------
 
-def _leaf_to_bytes(leaf) -> jax.Array:
-    # bitcast appends a trailing itemsize axis (none for 1-byte dtypes);
-    # C-order flatten then matches numpy's reshape(-1).view(uint8)
-    return jax.lax.bitcast_convert_type(leaf, jnp.uint8).reshape(-1)
+def _retype(x, dt) -> jax.Array:
+    """Flatten ``x`` and bitcast it to dtype ``dt``, whichever of the
+    two is wider.  C order, so bit-exact with numpy's ``.view``."""
+    dt = jnp.dtype(dt)
+    k, it = x.dtype.itemsize, dt.itemsize
+    x = x.reshape(-1, it // k) if it > k else x.reshape(-1)
+    return jax.lax.bitcast_convert_type(x, dt).reshape(-1)
 
 
-def _bytes_to_leaf(seg, spec: LeafSpec) -> jax.Array:
-    dt = jnp.dtype(spec.dtype)
-    if dt.itemsize == 1:
-        return jax.lax.bitcast_convert_type(seg, dt).reshape(spec.shape)
-    return jax.lax.bitcast_convert_type(
-        seg.reshape(-1, dt.itemsize), dt).reshape(spec.shape)
+def _segment(page, sp: LeafSpec) -> jax.Array:
+    """Leaf ``sp`` out of one 1-D page of any word width."""
+    k = page.dtype.itemsize
+    seg = jax.lax.slice(page, (sp.offset // k,),
+                        ((sp.offset + sp.nbytes) // k,))
+    return _retype(seg, sp.dtype).reshape(sp.shape)
 
 
 def _resolve_mode(mode: str) -> str:
@@ -182,30 +217,45 @@ def _resolve_mode(mode: str) -> str:
     return mode
 
 
+def _check_width(layout: PageLayout, buf, width: int) -> None:
+    k = buf.dtype.itemsize
+    if buf.shape[-1] * k != width:
+        raise ValueError(f"page width {buf.shape[-1] * k}B != {width}B")
+    if k > layout.word_bytes:
+        raise ValueError(f"{buf.dtype} pages cut leaves of a layout "
+                         f"aligned to {layout.word_bytes}B")
+
+
 def _normalize_pages(layout: PageLayout, pages,
                      width: Optional[int] = None):
-    """Accept either a (G, width) uint8 array or a sequence of
-    ``(buf, row)`` entries — ``buf`` a (width,) page (row None) or
-    a (Gk, width) staged group with ``row`` selecting one page —
-    and return (bufs tuple, rows int32 array, G).  ``width`` defaults
-    to the logical page size; a codec install passes its encoded size."""
+    """Accept either a (G, words) array or a sequence of ``(buf, row)``
+    entries — ``buf`` a (words,) page (row None) or a (Gk, words) staged
+    group with ``row`` selecting one page — and return (bufs tuple, rows
+    int32 array, G).  A page is uint8 bytes or unsigned words of at most
+    ``layout.word_bytes``; ``width`` is its size in bytes, by default
+    the logical page size (a codec install passes its encoded size)."""
     width = layout.page_bytes if width is None else width
     if hasattr(pages, "ndim"):
         if pages.ndim == 1:
             pages = pages[None]
         G = pages.shape[0]
-        if pages.shape[1] != width:
-            raise ValueError(f"page width {pages.shape[1]} != {width}")
+        _check_width(layout, pages, width)
         bufs = tuple(pages[g] for g in range(G))
         rows = jnp.zeros((G,), jnp.int32)
         return bufs, rows, G
     bufs, rows = [], []
     for buf, row in pages:
-        if buf.shape[-1] != width:
-            raise ValueError(f"page width {buf.shape[-1]} != {width}")
+        _check_width(layout, buf, width)
         bufs.append(buf)
         rows.append(0 if row is None else int(row))
     return tuple(bufs), jnp.asarray(rows, jnp.int32), len(bufs)
+
+
+def _page_rows(bufs, rows) -> list:
+    """The 1-D page of every entry (row picked on device)."""
+    return [b if b.ndim == 1
+            else jax.lax.dynamic_index_in_dim(b, rows[g], 0, keepdims=False)
+            for g, b in enumerate(bufs)]
 
 
 # ---------------------------------------------------------------------------
@@ -230,10 +280,11 @@ def install_pages_ref(layout: PageLayout, batch_leaves, pages, slots):
     out = list(batch_leaves)
     for g in range(G):
         pg = bufs[g] if bufs[g].ndim == 1 else bufs[g][int(rows[g])]
+        k = pg.dtype.itemsize
         sl = int(slots[g])
         for sp in layout.leaves:
-            piece = jax.lax.slice(pg, (sp.offset,),
-                                  (sp.offset + sp.nbytes,))
+            piece = jax.lax.slice(pg, (sp.offset // k,),
+                                  ((sp.offset + sp.nbytes) // k,))
             val = piece.view(sp.dtype).reshape(sp.shape)
             b = out[sp.index]
             if sp.slot_axis is None:
@@ -254,7 +305,8 @@ def install_pages_ref(layout: PageLayout, batch_leaves, pages, slots):
 @functools.lru_cache(maxsize=None)
 def _pack_jit(layout: PageLayout):
     def fn(leaves):
-        return jnp.concatenate([_leaf_to_bytes(l) for l in leaves])
+        return jnp.concatenate([_retype(l, layout.word_dtype)
+                                for l in leaves])
     return jax.jit(fn)
 
 
@@ -286,10 +338,7 @@ def _install_jit(layout: PageLayout, buf_shapes: tuple, donate: bool,
     keep = None if only is None else frozenset(only)
 
     def fn(batch_leaves, bufs, rows, slots):
-        pages = [b if b.ndim == 1
-                 else jax.lax.dynamic_index_in_dim(b, rows[g], 0,
-                                                   keepdims=False)
-                 for g, b in enumerate(bufs)]
+        pages = _page_rows(bufs, rows)
         out = list(batch_leaves)
         for sp in layout.leaves:
             if keep is not None and sp.index not in keep:
@@ -299,9 +348,7 @@ def _install_jit(layout: PageLayout, buf_shapes: tuple, donate: bool,
                     val = codec.decode_segment_jnp(
                         pg, _codec_seg(codec, sp)).reshape(sp.shape)
                 else:
-                    seg = jax.lax.dynamic_slice(pg, (sp.offset,),
-                                                (sp.nbytes,))
-                    val = _bytes_to_leaf(seg, sp)
+                    val = _segment(pg, sp)
                 b = out[sp.index]
                 if sp.slot_axis is None:
                     out[sp.index] = jnp.maximum(b, val)
@@ -346,118 +393,94 @@ def _can_donate(leaves) -> bool:
 # pallas fused kernels
 # ---------------------------------------------------------------------------
 
-def _group_span(specs: Sequence[LeafSpec]) -> Tuple[int, int]:
-    lo = min(sp.offset for sp in specs)
-    hi = max(sp.offset + sp.nbytes for sp in specs)
-    return lo, hi
+def _page_leaf_view(pages, sp: LeafSpec) -> jax.Array:
+    """Leaf ``sp`` of every staged 1-D page, as a (G, *tiled leaf shape)
+    array of the leaf's dtype: an XLA slice + bitcast outside the kernel,
+    so the kernel moves typed rows and never touches bytes."""
+    return jnp.stack([_segment(pg, sp).reshape(_tiled(sp, sp.shape))
+                      for pg in pages])
 
 
-def _install_group_kernel(slots_ref, pages, *rest, specs, span_lo,
-                          n_buffers, n_pages):
-    """Scatter one dtype-group's leaves of all pages into the batch
-    cache.  §2 hazard discipline, minus the put leg (stores are
-    synchronous): wait get(g) -> scatter g -> start get(g+n_buffers)."""
+def _slot_row(sp: LeafSpec, slot) -> tuple:
+    return tuple(pl.ds(slot, 1) if i == sp.slot_axis else slice(None)
+                 for i in range(len(sp.batch_shape)))
+
+
+def _install_kernel(slots_ref, *refs, specs, n_buffers, n_pages):
+    """Copy each kernel leaf of every staged page into its slot row of
+    the aliased batch leaf.  Every copy is an HBM->HBM DMA, so the kernel
+    holds no VMEM whatever ``max_len`` is.  Page g's copies run on lane
+    g % n_buffers; a lane's previous page is waited before the lane is
+    reused, so at most ``n_buffers`` pages are in flight."""
     n = len(specs)
-    outs = rest[n:2 * n]        # aliased: rest[:n] are the inputs
-    scratch = rest[2 * n:]
-    bufs, sems = scratch[:n_buffers], scratch[n_buffers:]
-    k = jnp.dtype(specs[0].dtype).itemsize
+    srcs, outs, sems = refs[:n], refs[2 * n:3 * n], refs[3 * n]
 
-    def get(slot, g):
-        return pltpu.make_async_copy(pages.at[g], bufs[slot], sems[slot])
-
-    for s in range(min(n_buffers, n_pages)):
-        get(s, s).start()
+    def copies(g, lane):
+        sl = slots_ref[g]
+        return [pltpu.make_async_copy(srcs[j].at[g],
+                                      outs[j].at[_slot_row(sp, sl)],
+                                      sems.at[lane, j])
+                for j, sp in enumerate(specs)]
 
     def body(g, _):
-        slot = jax.lax.rem(g, n_buffers)
+        lane = jax.lax.rem(g, n_buffers)
 
-        def per_slot(s):
-            get(s, g).wait()
-            sl = slots_ref[g]
-            for j, sp in enumerate(specs):
-                off_w = (sp.offset - span_lo) // k
-                n_w = sp.nbytes // k
-                val = bufs[s][pl.ds(off_w, n_w)].reshape(sp.shape)
-                idx = tuple(pl.ds(sl, 1) if i == sp.slot_axis
-                            else slice(None)
-                            for i in range(len(sp.batch_shape)))
-                outs[j][idx] = val
-            nxt = g + n_buffers
+        @pl.when(g >= n_buffers)
+        def _reuse():
+            for c in copies(g - n_buffers, lane):
+                c.wait()
 
-            @pl.when(nxt < n_pages)
-            def _prefetch():
-                get(s, nxt).start()
-
-        jax.lax.switch(slot, [functools.partial(per_slot, s)
-                              for s in range(n_buffers)])
+        for c in copies(g, lane):
+            c.start()
         return 0
 
     jax.lax.fori_loop(0, n_pages, body, 0)
-
-
-def _pages_as_words(pages2d, lo: int, hi: int, dtype) -> jax.Array:
-    """Byte-slice the staged pages to one dtype-group's span and bitcast
-    to that dtype's words (alignment guaranteed by kernel_groups)."""
-    k = jnp.dtype(dtype).itemsize
-    G = pages2d.shape[0]
-    span = jax.lax.slice(pages2d, (0, lo), (G, hi))
-    if k == 1:
-        return jax.lax.bitcast_convert_type(span, dtype)
-    return jax.lax.bitcast_convert_type(
-        span.reshape(G, (hi - lo) // k, k), dtype)
-
-
-def _install_pallas(layout: PageLayout, batch_leaves, bufs, rows, slots,
-                    n_buffers: int, interpret: bool):
-    # materialize the (G, page_bytes) staging view once (row selection
-    # fused into one program), then one pallas_call per dtype group
-    G = len(bufs)
-    stack = _stack_pages(tuple(b.shape for b in bufs))(bufs, rows)
-    slots_i32 = jnp.asarray(slots, jnp.int32)
-    out = list(batch_leaves)
-    for dt, specs in sorted(layout.kernel_groups().items()):
-        lo, hi = _group_span(specs)
-        words = _pages_as_words(stack, lo, hi, jnp.dtype(dt))
-        nb = max(1, min(n_buffers, G))
-        kernel = functools.partial(
-            _install_group_kernel, specs=tuple(specs), span_lo=lo,
-            n_buffers=nb, n_pages=G)
-        n = len(specs)
-        res = pl.pallas_call(
-            kernel,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                      pl.BlockSpec(memory_space=pl.ANY)]
-                     + [pl.BlockSpec(memory_space=pl.ANY)] * n,
-            out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n,
-            out_shape=[jax.ShapeDtypeStruct(sp.batch_shape,
-                                            jnp.dtype(sp.dtype))
-                       for sp in specs],
-            input_output_aliases={2 + j: j for j in range(n)},
-            scratch_shapes=(
-                [pltpu.VMEM((words.shape[1],), jnp.dtype(dt))] * nb
-                + [pltpu.SemaphoreType.DMA] * nb),
-            interpret=interpret,
-        )(slots_i32, words, *[out[sp.index] for sp in specs])
-        for j, sp in enumerate(specs):
-            out[sp.index] = res[j]
-    rest = layout.fallback_indices()
-    if rest:
-        fb = _install_jit(layout, tuple(b.shape for b in bufs),
-                          False, rest)
-        out = list(fb(tuple(out), bufs, rows, slots_i32))
-    return out
+    for g in range(max(0, n_pages - n_buffers), n_pages):
+        for c in copies(g, g % n_buffers):
+            c.wait()
 
 
 @functools.lru_cache(maxsize=None)
-def _stack_pages(buf_shapes: tuple):
-    def fn(bufs, rows):
-        return jnp.stack([
-            b if b.ndim == 1
-            else jax.lax.dynamic_index_in_dim(b, rows[g], 0,
-                                              keepdims=False)
-            for g, b in enumerate(bufs)])
-    return jax.jit(fn)
+def _install_pallas(layout: PageLayout, buf_shapes: tuple, n_buffers: int,
+                    interpret: bool, donate: bool):
+    """One program per (layout, staging shape): view each kernel leaf's
+    segment of the staged rows, run the DMA kernel over the aliased
+    batch leaves, then install the non-kernel leaves."""
+    specs = layout.kernel_leaves()
+    rest = layout.fallback_indices()
+    G = len(buf_shapes)
+    nb = max(1, min(n_buffers, G))
+    n = len(specs)
+    kernel = functools.partial(_install_kernel, specs=specs, n_buffers=nb,
+                               n_pages=G)
+    call = pl.pallas_call(
+        kernel,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+                 + [pl.BlockSpec(memory_space=pltpu.HBM)] * (2 * n),
+        out_specs=[pl.BlockSpec(memory_space=pltpu.HBM)] * n,
+        out_shape=[jax.ShapeDtypeStruct(_tiled(sp, sp.batch_shape),
+                                        jnp.dtype(sp.dtype))
+                   for sp in specs],
+        input_output_aliases={1 + n + j: j for j in range(n)},
+        scratch_shapes=[pltpu.SemaphoreType.DMA((nb, n))],
+        interpret=interpret,
+    )
+    fb = _install_jit(layout, buf_shapes, False, rest) if rest else None
+
+    def fn(batch_leaves, bufs, rows, slots):
+        pages = _page_rows(bufs, rows)
+        out = list(batch_leaves)
+        if specs:
+            res = call(slots, *[_page_leaf_view(pages, sp) for sp in specs],
+                       *[out[sp.index].reshape(_tiled(sp, sp.batch_shape))
+                         for sp in specs])
+            for sp, r in zip(specs, res):
+                out[sp.index] = r.reshape(sp.batch_shape)
+        if fb is not None:
+            out = list(fb(tuple(out), bufs, rows, slots))
+        return tuple(out)
+
+    return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 
 @functools.lru_cache(maxsize=None)
@@ -466,87 +489,49 @@ def _decode_stack(codec, buf_shapes: tuple):
     logical byte image, feeding the pallas scatter kernels (the dequant
     stays device-side; only the scatter itself runs in pallas)."""
     def fn(bufs, rows):
-        pages = [b if b.ndim == 1
-                 else jax.lax.dynamic_index_in_dim(b, rows[g], 0,
-                                                   keepdims=False)
-                 for g, b in enumerate(bufs)]
-        return jnp.stack([codec.decode_row_jnp(p) for p in pages])
+        return jnp.stack([codec.decode_row_jnp(p)
+                          for p in _page_rows(bufs, rows)])
     return jax.jit(fn)
 
 
-def _pack_group_kernel(*refs, specs, span_lo):
-    """Gather one dtype-group's leaves into a contiguous span image:
-    all leaf DMAs start up front (each staging buffer is used exactly
-    once — no reuse hazard), then each leaf's copy is waited and its
-    words stored as soon as it lands, overlapping DMA-in of the rest."""
-    n = len(specs)
-    ins = refs[:n]
-    out = refs[n]
-    scratch = refs[n + 1:]
-    bufs, sems = scratch[:n], scratch[n:]
-    k = jnp.dtype(specs[0].dtype).itemsize
-    copies = [pltpu.make_async_copy(ins[j], bufs[j], sems[j])
+def _pack_kernel(*refs, n):
+    """Copy each kernel leaf HBM->HBM into its own output: all DMAs
+    start up front (disjoint destinations, no reuse hazard), then all
+    are waited."""
+    ins, outs, sems = refs[:n], refs[n:2 * n], refs[2 * n]
+    copies = [pltpu.make_async_copy(ins[j], outs[j], sems.at[j])
               for j in range(n)]
     for c in copies:
         c.start()
-    # zero the span image while the DMAs fly: gap words (bytes owned by
-    # other dtype groups) must read 0 for the stitch's disjoint add
-    out[...] = jnp.zeros(out.shape, out.dtype)
-    for j, sp in enumerate(specs):
-        copies[j].wait()
-        off_w = (sp.offset - span_lo) // k
-        n_w = sp.nbytes // k
-        out[pl.ds(off_w, n_w)] = bufs[j][...].reshape(-1)
-
-
-def _pack_pallas(layout: PageLayout, leaves, n_buffers: int,
-                 interpret: bool):
-    """Fused device-side pack: one gather kernel per dtype group writes
-    its span image (gaps zeroed), then a single jitted stitch adds the
-    byte images into the final page — non-kernel leaves take the
-    bitcast-concat path for their segments."""
-    groups = sorted(layout.kernel_groups().items())
-    images = []
-    for dt, specs in groups:
-        lo, hi = _group_span(specs)
-        k = jnp.dtype(dt).itemsize
-        kernel = functools.partial(_pack_group_kernel, specs=tuple(specs),
-                                   span_lo=lo)
-        img = pl.pallas_call(
-            kernel,
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(specs),
-            out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            out_shape=jax.ShapeDtypeStruct(((hi - lo) // k,),
-                                           jnp.dtype(dt)),
-            scratch_shapes=(
-                [pltpu.VMEM(sp.shape, jnp.dtype(dt)) for sp in specs]
-                + [pltpu.SemaphoreType.DMA] * len(specs)),
-            interpret=interpret,
-        )(*[leaves[sp.index] for sp in specs])
-        images.append((lo, hi, img))
-    spans = tuple((lo, hi) for lo, hi, _ in images)
-    rest = layout.fallback_indices()
-    return _pack_stitch(layout, spans, rest)(
-        tuple(img for _, _, img in images),
-        tuple(leaves[i] for i in rest))
+    for c in copies:
+        c.wait()
 
 
 @functools.lru_cache(maxsize=None)
-def _pack_stitch(layout: PageLayout, spans: tuple, rest: tuple):
-    """Merge dtype-group span images (disjoint nonzero bytes — gaps in
-    a span belong to other groups and are zero there) plus the
-    non-kernel leaves' segments into one uint8 page."""
-    by_index = {sp.index: sp for sp in layout.leaves}
+def _pack_pallas(layout: PageLayout, interpret: bool):
+    """Fused device-side pack: the DMA kernel gathers the kernel leaves,
+    and the same program lays every leaf's words end to end in layout
+    order (non-kernel leaves straight from their inputs)."""
+    specs = layout.kernel_leaves()
+    n = len(specs)
+    call = pl.pallas_call(
+        functools.partial(_pack_kernel, n=n),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)] * n,
+        out_specs=[pl.BlockSpec(memory_space=pltpu.HBM)] * n,
+        out_shape=[jax.ShapeDtypeStruct(sp.shape, jnp.dtype(sp.dtype))
+                   for sp in specs],
+        scratch_shapes=[pltpu.SemaphoreType.DMA((n,))],
+        interpret=interpret,
+    )
 
-    def fn(images, rest_leaves):
-        page = jnp.zeros((layout.page_bytes,), jnp.uint8)
-        for (lo, hi), img in zip(spans, images):
-            page = page.at[lo:hi].add(_leaf_to_bytes(img))
-        for i, leaf in zip(rest, rest_leaves):
-            sp = by_index[i]
-            page = jax.lax.dynamic_update_slice(
-                page, _leaf_to_bytes(leaf), (sp.offset,))
-        return page
+    def fn(leaves):
+        merged = list(leaves)
+        if specs:
+            res = call(*[leaves[sp.index] for sp in specs])
+            for sp, r in zip(specs, res):
+                merged[sp.index] = r
+        return jnp.concatenate([_retype(l, layout.word_dtype)
+                                for l in merged])
 
     return jax.jit(fn)
 
@@ -556,11 +541,11 @@ def _pack_stitch(layout: PageLayout, spans: tuple, rest: tuple):
 # ---------------------------------------------------------------------------
 
 def pack_page(layout: PageLayout, leaves, *, mode: str = "auto",
-              n_buffers: int = 2,
-              interpret: Optional[bool] = None) -> jax.Array:
-    """Pack one slot's cache leaves into a (page_bytes,) uint8 page on
-    device.  The caller's single ``np.asarray`` is then the spill's only
-    D2H hop.  Bit-identical to ``pack_page_ref`` in every mode."""
+              interpret: Optional[bool] = None) -> np.ndarray:
+    """Pack one slot's cache leaves into a (page_bytes,) uint8 host page:
+    the jit and pallas modes pack on device, in ``layout.word_dtype``
+    words, and cross D2H once.  Bit-identical to ``pack_page_ref`` in
+    every mode."""
     mode = _resolve_mode(mode)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -569,10 +554,10 @@ def pack_page(layout: PageLayout, leaves, *, mode: str = "auto",
         raise ValueError(f"{len(leaves)} leaves != layout "
                          f"{len(layout.leaves)}")
     if mode == "ref":
-        return jnp.asarray(pack_page_ref(layout, leaves))
-    if mode == "jit":
-        return _pack_jit(layout)(leaves)
-    return _pack_pallas(layout, leaves, n_buffers, interpret)
+        return pack_page_ref(layout, leaves)
+    fn = _pack_jit(layout) if mode == "jit" \
+        else _pack_pallas(layout, interpret)
+    return np.asarray(fn(leaves)).view(np.uint8)
 
 
 def install_pages(layout: PageLayout, batch_leaves, pages, slots, *,
@@ -581,13 +566,14 @@ def install_pages(layout: PageLayout, batch_leaves, pages, slots, *,
                   donate: bool = False, codec=None):
     """Scatter G staged pages into the batch cache leaves at ``slots``.
 
-    ``pages``: a (G, page_bytes) uint8 array, or a sequence of
-    ``(buf, row)`` entries straight from ``TieredStore.ensure_packed``
-    (``buf`` a staged (Gk, page_bytes) group, ``row`` its page's row —
-    no per-row split ever happens).  Returns the new leaf list in
+    ``pages``: a (G, words) array, or a sequence of ``(buf, row)``
+    entries straight from ``TieredStore.ensure_packed`` (``buf`` a
+    staged (Gk, words) group, ``row`` its page's row — no per-row split
+    ever happens); words are uint8 or ``layout.word_dtype``.  Returns the new leaf list in
     tree-flatten order.  ``donate=True`` releases the old batch leaves
-    to XLA for in-place update (jit path; callers must drop their own
-    references).
+    to XLA for in-place update (callers must drop their own
+    references).  ``n_buffers`` bounds the pages whose copies the
+    pallas kernel keeps in flight; that path needs distinct slots.
 
     ``codec`` (a ``rmem.codec.PageCodec``) declares the staged pages
     codec-ENCODED (physical bytes, ``codec.encoded_bytes`` wide): the
@@ -619,17 +605,21 @@ def install_pages(layout: PageLayout, batch_leaves, pages, slots, *,
             return install_pages_ref(layout, batch_leaves,
                                      jnp.asarray(host), slots)
         return install_pages_ref(layout, batch_leaves, pages, slots)
+    donate = donate and _can_donate(batch_leaves)
     if mode == "pallas":
+        if len({int(s) for s in slots}) != G:
+            raise ValueError(f"pallas install needs distinct slots, got "
+                             f"{list(slots)}")
         if codec is not None:
             dec = _decode_stack(codec, tuple(b.shape for b in bufs))(
                 bufs, rows)
             bufs = tuple(dec[g] for g in range(G))
             rows = jnp.zeros((G,), jnp.int32)
-        return _install_pallas(layout, batch_leaves, bufs, rows, slots,
-                               n_buffers, interpret)
-    donate = donate and _can_donate(batch_leaves)
-    fn = _install_jit(layout, tuple(b.shape for b in bufs), donate, None,
-                      codec)
+        fn = _install_pallas(layout, tuple(b.shape for b in bufs),
+                             n_buffers, interpret, donate)
+    else:
+        fn = _install_jit(layout, tuple(b.shape for b in bufs), donate,
+                          None, codec)
     return list(fn(batch_leaves, bufs, rows,
                    jnp.asarray(slots, jnp.int32)))
 

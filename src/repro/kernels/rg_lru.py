@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import compiler_params_cls
-
 
 def _rg_lru_kernel(a_ref, b_ref, h0_ref, o_ref, carry_ref, *, bt: int):
     t_idx = pl.program_id(2)
@@ -65,7 +63,7 @@ def rg_lru_scan(a: jax.Array, b: jax.Array, h0: jax.Array = None, *,
         out_specs=pl.BlockSpec((1, bt, bw), lambda bi, wi, ti: (bi, ti, wi)),
         out_shape=jax.ShapeDtypeStruct((B, T, W), a.dtype),
         scratch_shapes=[pltpu.VMEM((bw,), a.dtype)],
-        compiler_params=compiler_params_cls()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b, h0)

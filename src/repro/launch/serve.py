@@ -55,6 +55,7 @@ from repro.configs import ARCHS, get_config, reduce_for_smoke
 from repro.faults import injector as _faults
 from repro.faults.injector import FaultPlan
 from repro.faults.retry import RetryPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving import (AdmissionController, FleetRouter, Request,
                            ServeEngine, Workload, default_tenants,
@@ -243,6 +244,7 @@ def main(argv=None) -> dict:
                          "re-route its queue to the survivors "
                          "(requires --replicas >= 2)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace_out:
         obs.trace.enable()

@@ -23,6 +23,7 @@ from repro.configs import ARCHS, get_config, reduce_for_smoke
 from repro.core.engine import MemoryEngine
 from repro.data.pipeline import (BatchSpec, DevicePrefetcher, PackedBatcher,
                                  SyntheticCorpus)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.models import transformer as T
 from repro.optim.adamw import AdamW
@@ -51,6 +52,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

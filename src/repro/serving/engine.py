@@ -314,6 +314,9 @@ class ServeEngine:
         # decode step they landed in (when the kill hit, relative to
         # decode progress — satellite of DESIGN.md §8)
         self.fabric_events: List[dict] = []
+        # pages are staged on device as the layout's widest words
+        # (PageLayout.word_dtype); the bytes are the same
+        words = self._install_layout().word_dtype
         if shared_path is not None:
             if total_pages is None:
                 total_pages = page_base + batch_slots
@@ -333,8 +336,9 @@ class ServeEngine:
             fabric_owned = getattr(shared_path, "_members", None) \
                 is not None
             self.pager = TieredStore(
-                n_pages=total_pages, page_shape=(page_bytes,),
-                dtype="uint8", n_hot_slots=batch_slots, path=shared_path,
+                n_pages=total_pages,
+                page_shape=(page_bytes // words.itemsize,),
+                dtype=words.name, n_hot_slots=batch_slots, path=shared_path,
                 retry=None if fabric_owned else kv_retry,
                 integrity=kv_integrity,
                 codec=page_codec_for(cfg, max_len, kv_codec),
@@ -376,8 +380,9 @@ class ServeEngine:
             # failing over) internally, a tier-level policy on top would
             # multiply attempts for ops the fabric already gave up on
             self.pager = TieredStore(
-                n_pages=n_tier_pages, page_shape=(page_bytes,),
-                dtype="uint8",
+                n_pages=n_tier_pages,
+                page_shape=(page_bytes // words.itemsize,),
+                dtype=words.name,
                 n_hot_slots=batch_slots, path=apath,
                 retry=kv_retry if self.fabric is None else None,
                 integrity=kv_integrity, codec=codec_obj,
@@ -509,12 +514,12 @@ class ServeEngine:
         ``np.concatenate``.  Identical bytes either way.
         """
         if self.fused_install:
-            page = ops.pack_page(self._install_layout(), leaves)
-            packed = np.asarray(page)
+            packed = ops.pack_page(self._install_layout(), leaves)
             self.install_hops_saved += max(0, len(leaves) - 1)
         else:
             packed = np.concatenate(
                 [np.asarray(l).reshape(-1).view(np.uint8) for l in leaves])
+        packed = packed.view(self._install_layout().word_dtype)
         if self.prefix_share and req.prefix_len > 0:
             # dedup the spill against the shared base for this prompt
             # prefix (§12): first writer publishes, later writers store
@@ -542,9 +547,11 @@ class ServeEngine:
         leaves.  Bit-exact by construction, so serving output is invariant
         to the backend."""
         dev_page = self.pager.ensure([self._pg(slot)])[self._pg(slot)]
+        k = dev_page.dtype.itemsize
         out, off = [], 0
         for l in leaves:
-            piece = jax.lax.slice(dev_page, (off,), (off + l.nbytes,))
+            piece = jax.lax.slice(dev_page, (off // k,),
+                                  ((off + l.nbytes) // k,))
             out.append(piece.view(l.dtype).reshape(l.shape))
             off += l.nbytes
         return jax.tree.unflatten(treedef, out)

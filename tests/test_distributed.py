@@ -28,6 +28,7 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, reduce_for_smoke
+from repro.launch.mesh import make_mesh
 from repro.models import lm, transformer as T
 from repro.optim.adamw import AdamW
 from repro.sharding import TRAIN_RULES, ShardCtx, tree_shardings
@@ -47,7 +48,7 @@ batch = {"tokens": jax.random.randint(key, (B, S), 0, cfg.vocab),
 ref_state, ref_metrics = jax.jit(lm.make_train_step(cfg, opt))(state, batch)
 
 # sharded: 4-way DP x 2-way TP
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 ctx = ShardCtx(mesh, TRAIN_RULES)
 defs = T.param_defs(cfg)
 p_ab = jax.tree.map(lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype), params)
@@ -82,11 +83,12 @@ import json, dataclasses
 import jax
 from repro.configs import get_config, reduce_for_smoke, SHAPES
 from repro.launch import dryrun as D
+from repro.launch.mesh import make_mesh
 from repro.launch.hlo import cost_analysis_dict, total_collective_bytes
 
 cfg = reduce_for_smoke(get_config("llama3-8b"))
 shape = dataclasses.replace(SHAPES["train_4k"], seq_len=64, global_batch=8)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 fn, ab = D._build(cfg, shape, mesh, "train", False)
 compiled = fn.lower(*ab).compile()
 total, per = total_collective_bytes(compiled.as_text())
@@ -114,6 +116,7 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, reduce_for_smoke
+from repro.launch.mesh import make_mesh
 from repro.models import lm, transformer as T
 from repro.optim.adamw import AdamW
 from repro.runtime.elastic import plan_resize
@@ -131,7 +134,7 @@ batch = {"tokens": jax.random.randint(key, (B, S), 0, cfg.vocab),
          "labels": jax.random.randint(key, (B, S), 0, cfg.vocab)}
 
 # world of 8 chips (4 workers x 2): train one step on (4,2) mesh
-mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+mesh8 = make_mesh((4, 2), ("data", "model"))
 step = jax.jit(lm.make_train_step(cfg, opt))
 state, m1 = step(state, batch)
 
@@ -141,7 +144,7 @@ with tempfile.TemporaryDirectory() as d:
     # "lose" two workers: replan to 4 chips and restore under the new mesh
     plan = plan_resize(alive_workers=[0, 1], chips_per_worker=2,
                        model_parallel=2, global_batch=B)
-    mesh4 = jax.make_mesh(plan.mesh_shape, plan.axis_names)
+    mesh4 = make_mesh(plan.mesh_shape, plan.axis_names)
     _, state2 = mgr.restore(state)
     sharded = jax.device_put(
         state2["params"],
