@@ -464,6 +464,7 @@ def _install_pallas(layout: PageLayout, buf_shapes: tuple, n_buffers: int,
         input_output_aliases={1 + n + j: j for j in range(n)},
         scratch_shapes=[pltpu.SemaphoreType.DMA((nb, n))],
         interpret=interpret,
+        name="kv_install_pages",
     )
     fb = _install_jit(layout, buf_shapes, False, rest) if rest else None
 
@@ -522,6 +523,7 @@ def _pack_pallas(layout: PageLayout, interpret: bool):
                    for sp in specs],
         scratch_shapes=[pltpu.SemaphoreType.DMA((n,))],
         interpret=interpret,
+        name="kv_pack_page",
     )
 
     def fn(leaves):

@@ -7,12 +7,14 @@
   (bounded relative error, mergeable) in a process-wide registry;
   ``obs.metrics.enable_live()`` additionally turns on hot-path wiring
   (per-completion reactor samples, ``stats()`` gauge mirrors).
+* ``obs.compiles`` — jax's backend compiles, while either switch is on,
+  as ``jit.compile`` spans and the ``jit.compiles`` counter.
 
 The module-level helpers (``obs.span``, ``obs.instant``, ...) are the
 instrumentation surface the rest of the repo calls; while everything is
 disabled they cost one global load and a ``None``/bool check.
 """
-from repro.obs import metrics, trace
+from repro.obs import compiles, metrics, trace
 from repro.obs.metrics import (Counter, Gauge, LogHistogram,
                                MetricsRegistry, default_registry,
                                export_stats)
@@ -27,7 +29,7 @@ def active() -> bool:
 
 
 __all__ = [
-    "trace", "metrics", "active",
+    "trace", "metrics", "compiles", "active",
     "span", "instant", "complete", "async_begin", "async_end",
     "Tracer", "get_tracer",
     "Counter", "Gauge", "LogHistogram", "MetricsRegistry",

@@ -31,9 +31,12 @@ _LIVE = False                   # hot-path wiring switch (not the registry)
 
 
 def enable_live() -> None:
-    """Turn on hot-path metric wiring (reactor samples, stats mirrors)."""
+    """Turn on hot-path metric wiring (reactor samples, stats mirrors,
+    the ``jit.compiles`` counter of ``obs.compiles``)."""
     global _LIVE
+    from repro.obs import compiles
     _LIVE = True
+    compiles.install()
 
 
 def disable_live() -> None:

@@ -25,10 +25,18 @@ Event vocabulary (DESIGN.md §8):
   across threads (the serve request lifecycle, which starts on the
   submitting caller and finishes inside the decode loop).
 
+While a tracer is installed, every ``span`` is also entered as a
+``jax.profiler.TraceAnnotation`` of the same name and args, so a
+profiler session (``jax.profiler.start_trace``) records the program's
+spans on its own host clock, beside the device ops.  Instants and
+``X``/async events stay in the ring only.
+
 Disabled-by-default no-op fast path: when no tracer is installed the
 module-level helpers cost one global load and a ``None`` check — no
-allocation, no locks — so instrumented hot paths stay hot.  ``enable()``
-installs the process tracer; ``export()`` writes the JSON.
+allocation, no locks, no annotation — so instrumented hot paths stay
+hot.  ``enable()`` installs the process tracer; ``export()`` writes the
+JSON.  ``jax`` is imported only when a tracer is built, so this module
+imports without it.
 """
 from __future__ import annotations
 
@@ -52,28 +60,68 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """Context manager emitting a B/E pair on the current thread track."""
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax is absent."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
-    __slots__ = ("_tracer", "_name", "_args")
+
+def _annotation_args(args: Optional[dict]) -> dict:
+    """Span args as a profiler annotation takes them: str, int and float
+    pass as they are, anything else (a list of rids, a numpy scalar)
+    as its string."""
+    if not args:
+        return {}
+    return {k: v if isinstance(v, (str, int, float)) else str(v)
+            for k, v in args.items()}
+
+
+class _Span:
+    """Context manager emitting a B/E pair on the current thread track,
+    mirrored into a profiler annotation of the same name and args."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_end_args", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._end_args = None
+        self._ann = None
 
     def __enter__(self):
         self._tracer._emit("B", self._name, self._tracer._thread_track(),
                            time.perf_counter(), None, self._args)
+        annotation = self._tracer.annotation
+        if annotation is not None:
+            self._ann = annotation(self._name,
+                                   **_annotation_args(self._args))
+            self._ann.__enter__()
         return self
 
+    def set(self, **args) -> None:
+        """Args known only inside the span: they ride its ``E`` event
+        (trace viewers merge them into the span) and its annotation."""
+        self._end_args = {**(self._end_args or {}), **args}
+        if self._ann is not None:
+            self._ann.set_metadata(**_annotation_args(args))
+
     def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         self._tracer._emit("E", self._name, self._tracer._thread_track(),
-                           time.perf_counter(), None, None)
+                           time.perf_counter(), None, self._end_args)
         return False
 
 
@@ -95,6 +143,8 @@ class Tracer:
         if limit < 1:
             raise ValueError(f"limit must be >= 1, got {limit}")
         self.limit = limit
+        # the profiler annotation every span mirrors into (None: no jax)
+        self.annotation = _profiler_annotation()
         self._events: deque = deque(maxlen=limit)
         self._lock = threading.Lock()
         self._tracks: Dict[Any, int] = {}       # key -> track id
@@ -205,9 +255,12 @@ def _json_default(obj):
 
 # -- module-level API (the no-op fast path) -------------------------------
 def enable(limit: int = 1 << 16) -> Tracer:
-    """Install (or replace) the process tracer; returns it."""
+    """Install (or replace) the process tracer; returns it.  Also hooks
+    jax's compile events into the obs plane (``obs.compiles``)."""
     global _TRACER
+    from repro.obs import compiles
     _TRACER = Tracer(limit=limit)
+    compiles.install()
     return _TRACER
 
 

@@ -511,25 +511,31 @@ class ServeEngine:
         Fused path: the pack runs as one on-device gather kernel and
         crosses C2H as ONE readback of the packed page; the per-leaf
         chain pays one blocking ``np.asarray`` per leaf plus a host
-        ``np.concatenate``.  Identical bytes either way.
+        ``np.concatenate``.  Identical bytes either way.  The pack and
+        the spill are the ``kv.pack`` and ``kv.spill`` spans, each
+        carrying the page's logical bytes.
         """
-        if self.fused_install:
-            packed = ops.pack_page(self._install_layout(), leaves)
-            self.install_hops_saved += max(0, len(leaves) - 1)
-        else:
-            packed = np.concatenate(
-                [np.asarray(l).reshape(-1).view(np.uint8) for l in leaves])
-        packed = packed.view(self._install_layout().word_dtype)
-        if self.prefix_share and req.prefix_len > 0:
-            # dedup the spill against the shared base for this prompt
-            # prefix (§12): first writer publishes, later writers store
-            # only the block delta — bit-exact reconstruction, so tokens
-            # are invariant to sharing being on
-            key = req.prompt[:req.prefix_len].tobytes()
-            ratio = self.pager.store_dedup(self._pg(slot), packed, key)
-            self._share_ratio += 0.5 * (ratio - self._share_ratio)
-        else:
-            self.pager.write_page(self._pg(slot), packed)
+        layout = self._install_layout()
+        with obs.span("kv.pack", rid=req.rid, bytes=layout.page_bytes):
+            if self.fused_install:
+                packed = ops.pack_page(layout, leaves)
+                self.install_hops_saved += max(0, len(leaves) - 1)
+            else:
+                packed = np.concatenate(
+                    [np.asarray(l).reshape(-1).view(np.uint8)
+                     for l in leaves])
+            packed = packed.view(layout.word_dtype)
+        with obs.span("kv.spill", rid=req.rid, bytes=layout.page_bytes):
+            if self.prefix_share and req.prefix_len > 0:
+                # dedup the spill against the shared base for this
+                # prompt prefix (§12): first writer publishes, later
+                # writers store only the block delta — bit-exact
+                # reconstruction, so tokens are invariant to sharing
+                key = req.prompt[:req.prefix_len].tobytes()
+                ratio = self.pager.store_dedup(self._pg(slot), packed, key)
+                self._share_ratio += 0.5 * (ratio - self._share_ratio)
+            else:
+                self.pager.write_page(self._pg(slot), packed)
         self._admit_spills.append(self._pg(slot))
 
     def _flush_spill_prefetch(self) -> None:
@@ -585,10 +591,11 @@ class ServeEngine:
                 jnp.arange(P, dtype=jnp.int32)[None, :, None], (1, P, 3))
         with obs.span("serve.prefill", rid=req.rid, slot=s,
                       prompt_len=P):
-            caches1 = T.init_cache(self.cfg, 1, self.max_len)
-            caches1, logits = self.prefill_1(self.params, batch,
-                                             caches1)
-            tok = int(jnp.argmax(logits[0]))
+            with obs.span("model.prefill", rid=req.rid):
+                caches1 = T.init_cache(self.cfg, 1, self.max_len)
+                caches1, logits = self.prefill_1(self.params, batch,
+                                                 caches1)
+                tok = int(jnp.argmax(logits[0]))
             if self.pager is not None:
                 leaves, treedef = jax.tree.flatten(caches1)
                 try:
@@ -745,29 +752,8 @@ class ServeEngine:
             self.blocking_installs += len(ready)
         else:
             pending = sorted(self._pending_install)
-            ready = [s for s in pending
-                     if self.pager.fetch_ready(self._pg(s))]
-            if not ready:
-                # nothing landed yet: with other slots decodable, grant a
-                # short grace (a fast fetch installs this step, a slow
-                # one overlaps the decode); with nothing decodable, block
-                # until the FIRST page lands, whichever it is.  Only
-                # reactive handles can settle on their own — a legacy
-                # eager PendingIO never will, so waiting on one would
-                # just burn the full timeout before the inline join
-                cs = [c for s in pending
-                      if (c := self.pager.fetch_completion(
-                          self._pg(s))) is not None
-                      and getattr(c, "reactive", True)]
-                if cs:
-                    try:
-                        cplane.wait_any(
-                            cs, timeout=self.overlap_grace_s
-                            if have_active else 60.0)
-                    except cplane.CompletionTimeout:
-                        pass
-                ready = [s for s in pending
-                         if self.pager.fetch_ready(self._pg(s))]
+            with obs.span("serve.install_wait", pending=len(pending)):
+                ready = self._settled(pending, have_active)
             if ready:
                 self.overlap_installs += len(ready)
             elif not have_active:
@@ -782,6 +768,28 @@ class ServeEngine:
         else:
             for s in ready:
                 self._install_one(s)
+
+    def _settled(self, pending: List[int], have_active: bool) -> List[int]:
+        """The pending slots whose fetch has settled.  If none has yet:
+        with other slots decodable, grant a short grace (a fast fetch
+        installs this step, a slow one overlaps the decode); with nothing
+        decodable, block until the FIRST page lands, whichever it is.
+        Only reactive handles can settle on their own — a legacy eager
+        PendingIO never will, so waiting on one would just burn the full
+        timeout before the inline join."""
+        ready = [s for s in pending if self.pager.fetch_ready(self._pg(s))]
+        if ready:
+            return ready
+        cs = [c for s in pending
+              if (c := self.pager.fetch_completion(self._pg(s))) is not None
+              and getattr(c, "reactive", True)]
+        if cs:
+            try:
+                cplane.wait_any(cs, timeout=self.overlap_grace_s
+                                if have_active else 60.0)
+            except cplane.CompletionTimeout:
+                pass
+        return [s for s in pending if self.pager.fetch_ready(self._pg(s))]
 
     def _install_one(self, s: int) -> None:
         """Per-leaf reference install for one slot: join its fetch, slice
@@ -887,56 +895,81 @@ class ServeEngine:
             self.fabric_events.append(ev)
 
     def step(self) -> int:
-        """One batched decode step; returns #active slots."""
+        """One batched decode step; returns #active slots.  The whole
+        step is the ``serve.step`` span; its children split admission,
+        the wait for page fetches, install, the decode's dispatch and
+        its sync, and retirement (DESIGN.md §8.1)."""
         self._step_no += 1
         t_step0 = time.perf_counter()
-        self._maybe_kill_node()
-        self._admit()
-        if self.pager is not None:
-            have_active = any(r is not None for r in self.slot_req)
-            self._install_ready(have_active)
-        self._drain_fabric_events()
-        active = [s for s in range(self.B) if self.slot_req[s] is not None]
-        if not active:
-            return 0
-        with obs.span("serve.decode_step", step=self._step_no,
-                      active=len(active)):
-            pos = jnp.asarray(self.slot_pos, jnp.int32)[:, None]
-            batch = {"tokens": jnp.asarray(self.cur_tokens)}
-            if self.cfg.attention is not None and \
-                    self.cfg.attention.mrope_sections is not None:
-                batch["pos"] = jnp.broadcast_to(pos[..., None],
-                                                (self.B, 1, 3))
-            else:
-                batch["pos"] = pos
-            self.caches, logits = self.decode(self.params, batch,
-                                              self.caches)
-            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        if self.admission is not None:
-            # the virtual-time clock: the full step duration (admission
-            # + install + decode — what a queued request actually waits
-            # through per step) advances admission's clock and feeds
-            # the cadence its TTFT prediction multiplies queue depth by
-            self.admission.observe_step(time.perf_counter() - t_step0,
-                                        active=len(active))
-        for s in active:
-            tok = int(nxt[s])
-            req = self.slot_req[s]
-            req.out_tokens.append(tok)
-            self.slot_pos[s] += 1
-            self.slot_left[s] -= 1
-            if self.slot_left[s] <= 0:
-                self._finish(req)
-                self.slot_req[s] = None
-                if self.pager is not None:
-                    self.pager.release(self._pg(s))
-                    # the retiring request's cold bytes return to the
-                    # soft budget (and its delta's base ref drops) —
-                    # what admission's refill draws against (§12)
-                    self.pager.discard_cold(self._pg(s))
-            else:
-                self.cur_tokens[s, 0] = tok
+        with obs.span("serve.step", step=self._step_no) as span:
+            self._maybe_kill_node()
+            with obs.span("serve.admit"):
+                self._admit()
+            if self.pager is not None:
+                have_active = any(r is not None for r in self.slot_req)
+                self._install_ready(have_active)
+            self._drain_fabric_events()
+            active = [s for s in range(self.B)
+                      if self.slot_req[s] is not None]
+            span.set(active=len(active))
+            if not active:
+                return 0
+            nxt = self._decode(len(active))
+            if self.admission is not None:
+                # the virtual-time clock: the full step duration
+                # (admission + install + decode — what a queued request
+                # actually waits through per step) advances admission's
+                # clock and feeds the cadence its TTFT prediction
+                # multiplies queue depth by
+                self.admission.observe_step(time.perf_counter() - t_step0,
+                                            active=len(active))
+            self._retire(active, nxt)
         return len(active)
+
+    def _decode(self, n_active: int) -> np.ndarray:
+        """One jitted decode of the whole batch; the next token of every
+        slot, on the host."""
+        with obs.span("serve.decode_step", step=self._step_no,
+                      active=n_active):
+            with obs.span("serve.decode.dispatch"):
+                pos = jnp.asarray(self.slot_pos, jnp.int32)[:, None]
+                batch = {"tokens": jnp.asarray(self.cur_tokens)}
+                if self.cfg.attention is not None and \
+                        self.cfg.attention.mrope_sections is not None:
+                    batch["pos"] = jnp.broadcast_to(pos[..., None],
+                                                    (self.B, 1, 3))
+                else:
+                    batch["pos"] = pos
+                self.caches, logits = self.decode(self.params, batch,
+                                                  self.caches)
+                nxt = jnp.argmax(logits, axis=-1)
+            with obs.span("serve.decode.sync"):
+                return np.asarray(nxt, np.int32)
+
+    def _retire(self, active: List[int], nxt: np.ndarray) -> None:
+        """Append each active slot's token; finish and free the slots
+        whose budget ran out (their pages leave the tier store)."""
+        with obs.span("serve.retire") as span:
+            finished = 0
+            for s in active:
+                tok = int(nxt[s])
+                req = self.slot_req[s]
+                req.out_tokens.append(tok)
+                self.slot_pos[s] += 1
+                self.slot_left[s] -= 1
+                if self.slot_left[s] <= 0:
+                    finished += 1
+                    self._finish(req)
+                    self.slot_req[s] = None
+                    if self.pager is not None:
+                        self.pager.release(self._pg(s))
+                        # the retiring request's cold bytes return to the
+                        # soft budget (and its delta's base ref drops) —
+                        # what admission's refill draws against (§12)
+                        self.pager.discard_cold(self._pg(s))
+                else:
+                    self.cur_tokens[s, 0] = tok
+            span.set(finished=finished)
 
     def idle(self) -> bool:
         """True when nothing is queued, backlogged, pending or active."""

@@ -102,6 +102,36 @@ def test_pack_page_compiles_for_v5e(one_chip, no_compile_cache, max_len):
     assert out.shape == (layout.page_bytes // layout.word_bytes,)
 
 
+@pytest.mark.parametrize("kernel", ["kv_install_pages", "kv_pack_page"])
+def test_page_kernels_carry_their_names(one_chip, no_compile_cache, kernel):
+    """The device trace names a kernel by its HLO op, which takes the
+    ``pallas_call``'s ``name``: ``kv_install_pages.N``, not
+    ``pallas_call.N``."""
+    layout = _layout(get_config(ARCH), SLOTS, 128)
+    if kernel == "kv_pack_page":
+        leaves = tuple(_on(one_chip, jax.ShapeDtypeStruct(sp.shape,
+                                                          sp.dtype))
+                       for sp in layout.leaves)
+        lowered = pi._pack_pallas(layout, interpret=False).lower(leaves)
+    else:
+        words = layout.page_bytes // layout.word_bytes
+        batch = tuple(_on(one_chip, jax.ShapeDtypeStruct(sp.batch_shape,
+                                                         sp.dtype))
+                      for sp in layout.leaves)
+        bufs = (_on(one_chip, jax.ShapeDtypeStruct((GROUP, words),
+                                                   layout.word_dtype)),)
+        vec = _on(one_chip, jax.ShapeDtypeStruct((1,), jnp.int32))
+        lowered = pi._install_pallas(layout, ((GROUP, words),),
+                                     n_buffers=2, interpret=False,
+                                     donate=True).lower(batch, bufs, vec,
+                                                        vec)
+    assert kernel in lowered.as_text()
+    calls = [line.split(" = ", 1)[0].strip()
+             for line in lowered.compile().as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(c.startswith(f"%{kernel}.") for c in calls), calls
+
+
 def test_full_width_decode_step_compiles_for_v5e(one_chip,
                                                  no_compile_cache):
     cfg = get_config(ARCH)
