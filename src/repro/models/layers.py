@@ -8,7 +8,8 @@ Attention comes in three compute paths:
   exact; ``unrolled=False`` uses ``lax.scan`` (compact HLO for full-config
   compiles and real training).
 * ``decode_attention``: single-token attention against a (possibly
-  sequence-sharded) KV cache.
+  sequence-sharded) KV cache, read only: the token's own row is attended
+  beside it and written afterwards (``write_kv_rows``).
 * Pallas flash attention (``repro.kernels``) — TPU target path, selected via
   ``ModelConfig.use_pallas``.
 """
@@ -216,8 +217,15 @@ def attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      cur_len: jax.Array, *, scale: Optional[float] = None,
-                     logit_cap: Optional[float] = None) -> jax.Array:
+                     logit_cap: Optional[float] = None,
+                     new_kv: Optional[Tuple[jax.Array, jax.Array,
+                                            jax.Array]] = None) -> jax.Array:
     """One-token attention. q: (B,1,H,dh); caches: (B,S,KV,dh).
+
+    ``new_kv = (k, v, idx)`` ((B,KV,dh), (B,KV,dh), (B,)) attends the
+    token's own key and value in place of cache row ``idx`` without
+    writing it: the same keys and softmax as attending over the cache
+    with the row written, while the cache stays a read-only input.
 
     The cache sequence dim may be sharded over the ``model`` mesh axis
     (flash-decode style); XLA inserts the partial-softmax reductions.
@@ -228,13 +236,28 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     scale = scale if scale is not None else 1.0 / (dh ** 0.5)
     qg = q.reshape(B, KV, G, dh)
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache).astype(jnp.float32) * scale
+    if new_kv is not None:
+        k_new, v_new, idx = new_kv
+        at = (jnp.arange(S)[None, :] == idx.reshape(-1, 1)
+              )[:, None, None, :]                           # (B,1,1,S)
+        s_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new).astype(
+            jnp.float32) * scale
+        s = jnp.where(at, s_new[..., None], s)
     if logit_cap is not None:
         s = logit_cap * jnp.tanh(s / logit_cap)
     mask = (jnp.arange(S)[None, :] < cur_len.reshape(-1, 1)
             )[:, None, None, :]                             # (B,1,1,S)
     s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bskd->bkgd", p.astype(v_cache.dtype), v_cache)
+    if new_kv is None:
+        o = jnp.einsum("bkgs,bskd->bkgd", p.astype(v_cache.dtype), v_cache)
+    else:
+        p_new = jnp.sum(jnp.where(at, p, 0.0), axis=-1)     # (B,KV,G)
+        p = jnp.where(at, 0.0, p).astype(v_cache.dtype)
+        o = jnp.einsum("bkgs,bskd->bkgd", p, v_cache,
+                       preferred_element_type=jnp.float32)
+        o = (o + p_new[..., None] * v_new.astype(jnp.float32)[:, :, None]
+             ).astype(v_new.dtype)
     return o.reshape(B, 1, H, dh)
 
 
@@ -265,7 +288,11 @@ def attention_apply(cfg: ModelConfig, p: dict, x: jax.Array, *,
                     mode: str, pos: jax.Array,
                     cache: Optional[dict] = None,
                     unrolled: bool = False):
-    """Returns (out, new_cache). cache = {"k","v": (B,Smax,KV,dh), "len": ()}"""
+    """Returns (out, new_cache). cache = {"k","v": (B,Smax,KV,dh), "len": (B,)}
+
+    In decode, ``new_cache`` holds only the step's rows ("k","v": (B,KV,dh),
+    int8 scales, and "len"), for ``write_kv_rows``; with ``unrolled`` it is
+    the whole written cache."""
     a = cfg.attention
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
@@ -324,37 +351,37 @@ def attention_apply(cfg: ModelConfig, p: dict, x: jax.Array, *,
                     vs, v_sc = _kv_quantize(v)
                     new_cache.update({"k": ks, "v": vs,
                                       "k_scale": k_sc, "v_scale": v_sc})
-    else:  # decode: single token per row, scattered into per-row positions
+    else:  # decode: one token per batch row, at that row's slot
         assert cache is not None and q.shape[1] == 1
         cur = cache["len"]                                  # (B,)
         Sbuf = cache["k"].shape[1]
-        idx = jnp.mod(cur, Sbuf) if a.window is not None and Sbuf <= \
-            (a.window or 0) else jnp.minimum(cur, Sbuf - 1)
-        rows = jnp.arange(k.shape[0])
-        # scatter: writes ONE row per batch element (aliasable in place on a
-        # donated cache) — the where()-rewrite it replaces materialised a
-        # full second KV copy per layer (EXPERIMENTS.md §Perf, decode iter 1)
-        ks, vs = k[:, 0], v[:, 0]
-        new_cache = {"len": cur + 1}
+        idx = decode_slot(cfg, cur, Sbuf)
+        eff = jnp.minimum(cur + 1, Sbuf)
+        rows = {"k": k[:, 0], "v": v[:, 0]}
         if kv_int8:
             kq, k_sc = _kv_quantize(k)
             vq, v_sc = _kv_quantize(v)
-            kc = cache["k"].at[rows, idx].set(kq[:, 0])
-            vc = cache["v"].at[rows, idx].set(vq[:, 0])
-            k_scc = cache["k_scale"].at[rows, idx].set(k_sc[:, 0])
-            v_scc = cache["v_scale"].at[rows, idx].set(v_sc[:, 0])
-            new_cache.update({"k": kc, "v": vc,
-                              "k_scale": k_scc, "v_scale": v_scc})
-            kd = _kv_dequantize(kc, k_scc, x.dtype)
-            vd = _kv_dequantize(vc, v_scc, x.dtype)
+            rows = {"k": kq[:, 0], "v": vq[:, 0],
+                    "k_scale": k_sc[:, 0], "v_scale": v_sc[:, 0]}
+        rows["len"] = cur + 1
+        if unrolled:
+            # dry-run cost path: a per-layer cache, written by one scatter
+            # and attended over whole, as its cost was always counted
+            b = jnp.arange(x.shape[0])
+            new_cache = {key: t if key == "len" else
+                         cache[key].at[b, idx].set(t)
+                         for key, t in rows.items()}
+            kd, vd = _kv_view(new_cache, x.dtype)
+            o = decode_attention(q, kd, vd, eff, scale=a.softmax_scale,
+                                 logit_cap=a.logit_cap)
         else:
-            kc = cache["k"].at[rows, idx].set(ks)
-            vc = cache["v"].at[rows, idx].set(vs)
-            new_cache.update({"k": kc, "v": vc})
-            kd, vd = kc, vc
-        eff = jnp.minimum(cur + 1, Sbuf)
-        o = decode_attention(q, kd, vd, eff, scale=a.softmax_scale,
-                             logit_cap=a.logit_cap)
+            # the cache is read only; the caller writes the returned rows
+            # (``write_kv_rows``), once for every stacked layer
+            kd, vd = _kv_view(cache, x.dtype)
+            kn, vn = _kv_view(rows, x.dtype)
+            o = decode_attention(q, kd, vd, eff, scale=a.softmax_scale,
+                                 logit_cap=a.logit_cap, new_kv=(kn, vn, idx))
+            new_cache = rows
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     return out, new_cache
 
@@ -375,6 +402,56 @@ def attention_cache_defs(cfg: ModelConfig, batch: int, max_len: int) -> dict:
         defs["k_scale"] = sc
         defs["v_scale"] = sc
     return defs
+
+
+KV_ROW_KEYS = ("k", "v", "k_scale", "v_scale")  # written one row per step
+
+
+def decode_slot(cfg: ModelConfig, cur: jax.Array, Sbuf: int) -> jax.Array:
+    """The cache row a decode step writes for lengths ``cur``: the ring
+    slot of a window cache, else the next position (the last row once
+    the buffer is full)."""
+    a = cfg.attention
+    if a.window is not None and Sbuf <= a.window:
+        return jnp.mod(cur, Sbuf)
+    return jnp.minimum(cur, Sbuf - 1)
+
+
+def write_kv_rows(cfg: ModelConfig, cache: dict, rows: dict) -> dict:
+    """``cache`` after one decode step: the KV rows of ``rows`` (the decode
+    output of ``attention_apply``, with the leading layer axes of
+    ``cache["len"]``) written at each batch row's slot.
+
+    Every layer of a batch row holds the same length (prefill, install
+    and decode write all layers together), so each batch row is one
+    ``dynamic_update_slice`` across the layer axes.  On a donated cache
+    that writes in place in whatever layout the leaf has; a scatter makes
+    the TPU compiler copy the whole leaf to a row-major layout and back.
+    """
+    cur = cache["len"]
+    lead = cur.ndim - 1
+    idx = decode_slot(cfg, cur, cache["k"].shape[cur.ndim])[(0,) * lead]
+    out = {"len": rows["len"]}
+    for key in KV_ROW_KEYS:
+        if key not in cache:
+            continue
+        leaf, new = cache[key], rows[key]
+        for b in range(cur.shape[-1]):
+            row = jnp.expand_dims(new[(slice(None),) * lead + (b,)],
+                                  (lead, lead + 1))
+            start = (0,) * lead + (b, idx[b]) + (0,) * (leaf.ndim - lead - 2)
+            leaf = jax.lax.dynamic_update_slice(leaf, row.astype(leaf.dtype),
+                                                start)
+        out[key] = leaf
+    return out
+
+
+def _kv_view(c: dict, dtype):
+    """The (k, v) attention reads from cache leaves ``c``."""
+    if "k_scale" in c:
+        return (_kv_dequantize(c["k"], c["k_scale"], dtype),
+                _kv_dequantize(c["v"], c["v_scale"], dtype))
+    return c["k"], c["v"]
 
 
 def _kv_quantize(t: jax.Array):

@@ -21,7 +21,8 @@ from repro.models import rglru as rglru_mod
 from repro.models import rwkv as rwkv_mod
 from repro.models.layers import (ParamDef, attention_apply,
                                  attention_cache_defs, attention_defs,
-                                 mlp_apply, mlp_defs, rms_norm)
+                                 mlp_apply, mlp_defs, rms_norm,
+                                 write_kv_rows)
 from repro.sharding import constrain
 
 # ---------------------------------------------------------------------------
@@ -218,9 +219,24 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int):
 # forward over the whole stack
 # ---------------------------------------------------------------------------
 
+def _write_decode_rows(cfg: ModelConfig, cache, rows):
+    """A block's cache after a decode step, from ``block_apply``'s new
+    cache: attention KV gets the step's row written in place; recurrent
+    state, rewritten whole every step, is the new cache already."""
+    if "attn" not in rows:
+        return rows
+    return {"attn": write_kv_rows(cfg, cache["attn"], rows["attn"])}
+
+
 def apply_blocks(cfg: ModelConfig, params, x, *, mode: str, pos,
                  caches=None, unrolled: bool = False, ctx=None):
-    """Run every block. Returns (x, new_caches, aux_total)."""
+    """Run every block. Returns (x, new_caches, aux_total).
+
+    Decode keeps the stacked attention KV out of the scan's outputs: each
+    layer reads its K and V as scan inputs, the scan emits only the new
+    rows, and ``write_kv_rows`` writes them in place after it (DESIGN.md
+    §6.5).
+    """
     n_groups, n_tail = _group_layout(cfg)
     aux_total = jnp.zeros((), jnp.float32)
     new_caches: Dict[str, Any] = {}
@@ -309,6 +325,9 @@ def apply_blocks(cfg: ModelConfig, params, x, *, mode: str, pos,
                     # un-chunk the (n_super, span, ...) cache stacking
                     unresh = lambda t: t.reshape((n_groups,) + t.shape[2:])
                     ncs = jax.tree.map(unresh, ncs)
+                if mode == "decode":
+                    ncs = {b: _write_decode_rows(cfg, gcaches[b], nc)
+                           for b, nc in ncs.items()}
                 new_caches["groups"] = ncs
 
     if n_tail:
@@ -321,6 +340,8 @@ def apply_blocks(cfg: ModelConfig, params, x, *, mode: str, pos,
                                      mode=mode, pos=pos, cache=c,
                                      unrolled=unrolled, ctx=ctx)
             aux_total = aux_total + aux
+            if mode == "decode" and not unrolled:
+                nc = _write_decode_rows(cfg, c, nc)
             if nc is not None:
                 new_tail[f"t{i}"] = nc
         if new_tail:

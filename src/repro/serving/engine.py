@@ -66,13 +66,21 @@ _KV_BACKEND_ALIAS = {"local": "xdma", "remote": "verbs"}
 
 @functools.lru_cache(maxsize=None)
 def _jitted_steps(cfg):
-    """One jitted (prefill, decode) pair per config, shared by every
-    engine in the process.  jax keys its compilation cache on function
-    identity, so per-engine ``jax.jit(lm.make_*_step(cfg))`` wrappers
-    recompile the same XLA program once per replica (and once per run):
-    a 2-replica fleet would pay the whole compile bill twice."""
-    return (jax.jit(lm.make_prefill_step(cfg)),
-            jax.jit(lm.make_decode_step(cfg)))
+    """One jitted (prefill, decode, in-place decode) triple per config,
+    shared by every engine in the process.  jax keys its compilation
+    cache on function identity, so per-engine
+    ``jax.jit(lm.make_*_step(cfg))`` wrappers recompile the same XLA
+    program once per replica (and once per run): a 2-replica fleet would
+    pay the whole compile bill twice.
+
+    The in-place decode donates the batch cache (argument 2): the step
+    writes its KV rows into the cache, and the caller rebinds the cache
+    to the result and holds no leaf of the old tree (DESIGN.md §6.5).
+    The plain decode is ``ServeEngine.decode``, the step a caller may
+    wrap; both are named ``jit_decode``."""
+    decode = lm.make_decode_step(cfg)
+    return (jax.jit(lm.make_prefill_step(cfg)), jax.jit(decode),
+            jax.jit(decode, donate_argnums=(2,)))
 
 
 @dataclasses.dataclass
@@ -239,7 +247,9 @@ class ServeEngine:
         self.name = name
         self.queue: "queue.Queue[Request]" = queue.Queue()
         self.done: List[Request] = []
-        self.prefill_1, self.decode = _jitted_steps(cfg)
+        self.prefill_1, self.decode, self._decode_in_place = \
+            _jitted_steps(cfg)
+        self._decode_own = self.decode
         self.caches = T.init_cache(cfg, batch_slots, max_len)
         self.slot_req: List[Optional[Request]] = [None] * batch_slots
         self.slot_left = np.zeros(batch_slots, np.int64)
@@ -940,9 +950,19 @@ class ServeEngine:
                                                     (self.B, 1, 3))
                 else:
                     batch["pos"] = pos
-                self.caches, logits = self.decode(self.params, batch,
-                                                  self.caches)
+                # the engine's own decode consumes the cache in place; a
+                # step a caller put in its place runs as given, since it
+                # may hand back the cache it was given
+                step = self._decode_in_place \
+                    if self.decode is self._decode_own else self.decode
+                old = jax.tree.leaves(self.caches)
+                self.caches, logits = step(self.params, batch, self.caches)
                 nxt = jnp.argmax(logits, axis=-1)
+                if obs.metrics.live():
+                    # a silent loss of donation reads 0
+                    obs.default_registry().counter(
+                        "serve.decode.kv_donated_bytes").inc(
+                            sum(l.nbytes for l in old if l.is_deleted()))
             with obs.span("serve.decode.sync"):
                 return np.asarray(nxt, np.int32)
 

@@ -148,3 +148,30 @@ def test_full_width_decode_step_compiles_for_v5e(one_chip,
     mem = compiled.memory_analysis()
     # weights + KV cache + temporaries fit one v5e's 16 GB of HBM
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_in_place_decode_copies_no_cache_for_v5e(one_chip, no_compile_cache):
+    """The engine's decode writes its KV rows into the donated cache: the
+    program aliases every cache leaf to its output and neither copies a
+    whole leaf nor keeps a second cache among its temporaries."""
+    from repro.serving.engine import _jitted_steps
+    cfg = get_config(ARCH)
+    B, max_len = 8, 2048
+    params = _on(one_chip, jax.eval_shape(
+        lambda: T.tree_init(T.param_defs(cfg), cfg, jax.random.PRNGKey(0))))
+    caches = _on(one_chip, jax.eval_shape(
+        lambda: T.init_cache(cfg, B, max_len)))
+    batch = _on(one_chip, {
+        "tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32),
+        "pos": jax.ShapeDtypeStruct((B, 1), jnp.int32)})
+    compiled = _jitted_steps(cfg)[2].lower(params, batch, caches).compile()
+    cache_bytes = sum(l.size * l.dtype.itemsize
+                      for l in jax.tree.leaves(caches))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 0.01 * cache_bytes
+    leaf = "[{}]".format(",".join(
+        map(str, caches["groups"]["b0"]["attn"]["k"].shape)))
+    copies = [line for line in compiled.as_text().splitlines()
+              if " copy(" in line and leaf in line.split(" copy(")[0]]
+    assert not copies, copies[:2]
