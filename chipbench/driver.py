@@ -18,25 +18,14 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
-from chipbench import flops, weights
+from chipbench import spec, weights
 from chipbench.traffic_gen import Arrival
 
 
 def program_config(name: str, cfg: dict):
-    """The program's ``ModelConfig`` for a Qwen2-style configuration
-    file, every size and constant taken from the file."""
-    from repro.configs.base import AttentionCfg, ModelConfig
-    d, h, kv, dh, f, v, n = flops.dims(cfg)
-    return ModelConfig(
-        arch_id=name, family="dense", n_layers=n, d_model=d, d_ff=f,
-        vocab=v,
-        attention=AttentionCfg(n_heads=h, n_kv_heads=kv, d_head=dh,
-                               qkv_bias=True,
-                               rope_theta=float(cfg["rope_theta"])),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        act=cfg.get("hidden_act", "silu"),
-        dtype=cfg.get("torch_dtype", "bfloat16"))
+    """The program's ``ModelConfig`` for a configuration file, built by
+    the architecture module its ``reference`` names."""
+    return spec.arch(cfg["reference"]).program_config(name, cfg)
 
 
 def check_param_layout(pcfg, cfg: dict) -> None:
@@ -116,7 +105,10 @@ class Loop:
     ``run(arrivals, origin_pc, stop_submit_pc, grace_pc)`` submits every
     arrival due before ``stop_submit_pc`` at its due time, steps the
     engine while it has work, and returns once every submitted request
-    is done or ``grace_pc`` has passed.  ``on_step(start, end, grew)``
+    is done or ``grace_pc`` has passed.  ``on_tick(now)`` (if given) runs
+    at the top of each pass; the time it holds the loop (a profiler
+    writing its trace) is the harness's, so it moves ``grace_pc`` on and
+    never uses up the program's grace.  ``on_step(start, end, grew)``
     (if given) sees each step's start and end time and the ``(rec,
     first_index, count)`` of every request whose tokens grew.
     """
@@ -146,6 +138,7 @@ class Loop:
             now = time.perf_counter()
             if self.on_tick is not None:
                 self.on_tick(now)
+                grace_pc += time.perf_counter() - now
             while nxt < len(recs) and recs[nxt].due_pc <= now:
                 rec = recs[nxt]
                 with self._ann("bench.submit"):

@@ -10,7 +10,7 @@ embedding when ``tie_word_embeddings``).  No cache, no batching, no
 kernels: one sequence at a time, every matmul at
 ``Precision.HIGHEST`` so a TPU computes it in float32.
 
-Weights come in the benchmark's tree (``chipbench/weights.py``), which
+Weights come in the benchmark's tree (``chipbench/archs/qwen2.py``), which
 stacks the layers on a leading axis and stores each RMSNorm gain as its
 offset from 1, so the gain applied here is ``1 + w``.  The tree is made
 by the benchmark from the seed; nothing of the program is imported.

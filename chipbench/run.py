@@ -114,8 +114,10 @@ class Tracing:
         import jax
         if self.t1 is None:
             self._close_span()
+        t = time.perf_counter()
         jax.profiler.stop_trace()
         self.on = False
+        log(f"profiler stop held the loop {time.perf_counter() - t} s")
 
     def finish(self) -> None:
         """After the drain: end the profiler's span if it is still on,

@@ -3,10 +3,14 @@ configuration, traffic mix, per-layer metric readers and device peaks.
 
 Every piece lives in a file of its own under ``chipbench/`` and is found
 from the name ``BENCHMARK.json`` gives it, so a new cell, configuration,
-mix or metric is new files and entries, never an edit.
+mix or metric is new files and entries, never an edit.  A configuration's
+``reference`` names its family: the plain reference
+(``references/<name>.py``) and the architecture module
+(``archs/<name>.py``: program config, weight tree and counts).
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -45,6 +49,10 @@ def reference_path(name: str) -> str:
     return os.path.join(BENCH_DIR, "references", f"{name}.py")
 
 
+def arch_path(name: str) -> str:
+    return os.path.join(BENCH_DIR, "archs", f"{name}.py")
+
+
 def _load_module(path: str, modname: str):
     if not os.path.isfile(path):
         raise FileNotFoundError(path)
@@ -66,6 +74,21 @@ def metric_reader(name: str):
 def reference(name: str):
     """The plain reference module a configuration names."""
     return _load_module(reference_path(name), f"chipbench_ref_{name}")
+
+
+def arch(name: str):
+    """The architecture module a configuration names.  It defines
+    ``program_config(name, cfg)``, ``weight_shapes(cfg)``,
+    ``params(cfg)``, ``prefill_flops(cfg, prompt_len)``,
+    ``decode_flops(cfg, contexts)``, ``decode_bytes(cfg, contexts)`` and
+    ``page_live_bytes(cfg, prompt_len)``."""
+    return _cached_module(arch_path(name), f"chipbench_arch_{name}")
+
+
+@functools.lru_cache(maxsize=None)
+def _cached_module(path: str, modname: str):
+    # the counts are asked for once per traced step: load each file once
+    return _load_module(path, modname)
 
 
 def peaks(device_kind: str) -> dict:

@@ -99,3 +99,33 @@ def test_no_tpu_exits_nonzero(capsys):
                    "--seconds", "1", "--trace", "0"])
     assert rc != 0
     assert capsys.readouterr().out == ""
+
+
+def test_a_tick_that_holds_the_loop_uses_up_no_grace():
+    """A profiler writing its trace holds the loop in ``on_tick`` for
+    longer than the drain's grace: the requests still live are served
+    all the same, since that time is the harness's, not the program's."""
+    import time
+
+    from chipbench import driver, traffic_gen, weights
+    r = tiny.resolved(False)
+    cfg, cell = r["config"], r["cell"]
+    eng = driver.build_engine(driver.program_config("toy", cfg),
+                              weights.init(cfg, 5), cfg, cell)
+    driver.warm_engine(eng, traffic_gen.prompt_buckets(r["traffic"]),
+                       cfg["vocab_size"])
+    arrivals = traffic_gen.schedule(r["traffic"], 8.0, 1.0,
+                                    cfg["vocab_size"], 5)
+    origin = time.perf_counter()
+    held = []
+
+    def tick(now):
+        if not held and now >= origin + 0.5:
+            held.append(now)
+            time.sleep(3.0)
+
+    recs = driver.Loop(eng, on_tick=tick).run(arrivals, origin,
+                                              origin + 1.0, origin + 3.0)
+    driver.close_engine(eng)
+    assert held and len(recs) > 2
+    assert all(x.served for x in recs)

@@ -53,6 +53,7 @@ def test_cell_resolves_to_its_files(cell):
     assert os.path.isfile(spec.config_path(entry["config"]))
     assert os.path.isfile(spec.traffic_path(entry["traffic"]))
     assert os.path.isfile(spec.reference_path(r["config"]["reference"]))
+    assert os.path.isfile(spec.arch_path(r["config"]["reference"]))
     conf = next(c for c in BENCH["configs"] if c["name"] == entry["config"])
     assert conf["file"] == f"chipbench/configs/{entry['config']}.json"
     for m in r["end_to_end"] + r["per_layer"]:
@@ -60,6 +61,17 @@ def test_cell_resolves_to_its_files(cell):
     reported = {m["name"] for m in r["end_to_end"]}
     assert "setup_s" in reported and len(reported) >= 2
     assert r["per_layer"]
+
+
+CONFIG_FILES = sorted(f[:-len(".json")] for f in os.listdir(
+    os.path.join(spec.BENCH_DIR, "configs")) if f.endswith(".json"))
+
+
+@pytest.mark.parametrize("config", CONFIG_FILES)
+def test_config_names_its_family_files(config):
+    family = spec.load_json(spec.config_path(config))["reference"]
+    assert os.path.isfile(spec.reference_path(family))
+    assert os.path.isfile(spec.arch_path(family))
 
 
 def test_per_layer_metrics_move_what_their_cells_report():
